@@ -12,6 +12,7 @@ import time
 import traceback
 
 from benchmarks.common import emit_csv, save_rows
+from repro.utils.compile_cache import enable_compile_cache
 
 BENCHMARKS = [
     "table2_accuracy",   # paper Table 2
@@ -33,6 +34,7 @@ def main() -> int:
     ap.add_argument("--full", action="store_true", help="paper-scale sweeps")
     ap.add_argument("--only", default=None, choices=[*BENCHMARKS, None])
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [args.only] if args.only else BENCHMARKS
     failures = 0

@@ -362,7 +362,10 @@ def run_serve_chaos(args) -> tuple[dict, dict]:
 def main(argv=None) -> int:
     import jax
 
+    from repro.utils.compile_cache import enable_compile_cache
+
     args = build_args(argv)
+    enable_compile_cache()
     rows, crashes = run_matrix(args)
     serve, ckpt = run_serve_chaos(args)
     deltas = [r["acc_delta"] for r in rows if math.isfinite(r["acc_delta"])]

@@ -286,7 +286,10 @@ def run_pipeline(args) -> dict:
 
 
 def main(argv=None) -> int:
+    from repro.utils.compile_cache import enable_compile_cache
+
     args = build_args(argv)
+    enable_compile_cache()
     run_pipeline(args)
     return 0
 

@@ -37,8 +37,8 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.federated.quant import check_sync_dtype, quant_roundtrip
 
@@ -49,14 +49,19 @@ def make_client_mesh(n_devices: Optional[int] = None, *,
                      axis: str = CLIENT_AXIS) -> Mesh:
     """A flat ``(n_devices,)`` mesh with one client-sharding axis. On CPU,
     force fake devices first: ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    (before the JAX backend initializes)."""
+    (before the JAX backend initializes).
+
+    The axis is ``AxisType.Auto``, as on every mesh the engine builds: the
+    executors place arrays by ``NamedSharding`` and ``shard_map`` specs and
+    leave the rest to jit's propagation. (``jax.make_mesh`` defaults to
+    Explicit axes, which would carry a sharding in every array's type.)"""
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"make_client_mesh needs 1..{len(devs)} devices, asked for {n} "
             "(force more with XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((n,), (axis,), devices=devs[:n])
+    return jax.make_mesh((n,), (axis,), (AxisType.Auto,), devices=devs[:n])
 
 
 def client_axis_of(mesh: Mesh) -> Optional[str]:
@@ -109,22 +114,24 @@ def weighted_merge(axes, w, reduce: str):
     the carried ``old`` leaf instead of dividing 0/0 into NaN params.
     With any surviving weight the guard is exact: ``max(wsum, tiny)``
     equals ``wsum`` and the ``where`` passes the quotient through
-    bit-unchanged."""
+    bit-unchanged.
+
+    The quotient is ``num * (1 / wsum)``, not ``num / wsum``: XLA rewrites
+    ``FedAvg``'s ``mean`` (a division by the constant cohort size) into a
+    multiply by the rounded reciprocal, so this form keeps a one-device
+    mesh bit-identical to the unsharded merge under uniform weights."""
     if reduce == "psum":
         wsum = jax.lax.psum(w.sum(), axes)
-
-        def wmean(x, old):
-            wb = w.reshape((-1,) + (1,) * (x.ndim - 1))
-            num = jax.lax.psum((x * wb).sum(axis=0), axes)
-            return jnp.where(wsum > 0.0, num / jnp.maximum(wsum, 1e-12), old)
+        num_of = lambda xw: jax.lax.psum(xw, axes)
     else:   # "pairwise": association fixed by device count, not by XLA
         wsum = pairwise_sum(jax.lax.all_gather(w.sum(), axes))
+        num_of = lambda xw: pairwise_sum(jax.lax.all_gather(xw, axes, axis=0))
+    inv = 1.0 / jnp.maximum(wsum, 1e-12)
 
-        def wmean(x, old):
-            wb = w.reshape((-1,) + (1,) * (x.ndim - 1))
-            num = pairwise_sum(
-                jax.lax.all_gather((x * wb).sum(axis=0), axes, axis=0))
-            return jnp.where(wsum > 0.0, num / jnp.maximum(wsum, 1e-12), old)
+    def wmean(x, old):
+        wb = w.reshape((-1,) + (1,) * (x.ndim - 1))
+        num = num_of((x * wb).sum(axis=0))
+        return jnp.where(wsum > 0.0, num * inv, old)
     return wmean
 
 
@@ -149,7 +156,7 @@ def _client_step(vm, mesh: Mesh, axis: str, reduce: str):
         step, mesh=mesh,
         in_specs=(r, c, r, r, c, c, c, c, r, c, r, c, c),
         out_specs=(r, c, c, c, c),
-        check_rep=False)
+        check_vma=False)
 
 
 def build_sharded_chunk(vm, mesh: Mesh, axis: str, m_real: int,

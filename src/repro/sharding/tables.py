@@ -53,8 +53,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.federated.partition import GhostBuckets, pod_table_padding
 from repro.federated.quant import check_sync_dtype
@@ -82,7 +82,8 @@ def make_pod_mesh(n_pods: int, n_client_shards: Optional[int] = None) -> Mesh:
     """A ``(n_pods, n_client_shards)`` mesh with ``("pods", "clients")``
     axes: tables shard over the first, each round's cohort over both. With
     ``n_client_shards=None`` all visible devices are used (they must split
-    evenly). On CPU, force fake devices first:
+    evenly). Both axes are ``AxisType.Auto`` (see ``make_client_mesh``).
+    On CPU, force fake devices first:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     devs = jax.devices()
     if n_pods < 1:
@@ -100,7 +101,7 @@ def make_pod_mesh(n_pods: int, n_client_shards: Optional[int] = None) -> Mesh:
             f"{n_pods}x{n_client_shards} (force more with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     return jax.make_mesh((n_pods, n_client_shards), (POD_AXIS, CLIENT_AXIS),
-                         devices=devs[:n])
+                         (AxisType.Auto,) * 2, devices=devs[:n])
 
 
 def pod_axes_of(mesh: Mesh) -> Optional[tuple[str, str]]:
@@ -286,7 +287,7 @@ def _pod_step(vm, mesh: Mesh, buckets: GhostBuckets, reduce: str,
         in_specs=(r, t, t, t, t, t, t, r, r, c, r, c, c, r, r, r, r,
                   t, t, t, t, t, t),
         out_specs=(r, t, t, t, t, c),
-        check_rep=False)
+        check_vma=False)
 
 
 def build_pod_sharded_chunk(vm, mesh: Mesh, m_real: int,

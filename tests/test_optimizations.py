@@ -92,7 +92,8 @@ def test_dp_profile_replicates_params(key):
     from jax.sharding import PartitionSpec as P
     if len(jax.devices()) != 1:
         pytest.skip("single-device test")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     cfg = get_smoke_config("rwkv6-1.6b")
     shapes = jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
     specs = param_spec_tree(shapes, mesh, profile="dp")

@@ -16,6 +16,7 @@ runs this file under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.api import FedEngine, FedAvg, LossBiasedSelector, SyncScheduler, method_config
 from repro.sharding.fed import (
@@ -221,7 +222,8 @@ def test_engine_validates_sharding_options(small_fed):
     with pytest.raises(ValueError, match="client_sharding"):
         FedEngine(g, fed, method_config("fedais"), rounds=1,
                   client_sharding="sometimes")
-    two_axis = jax.make_mesh((1, 1), ("a", "b"), devices=jax.devices()[:1])
+    two_axis = jax.make_mesh((1, 1), ("a", "b"), (AxisType.Auto,) * 2,
+                             devices=jax.devices()[:1])
     with pytest.raises(ValueError, match="clients"):
         FedEngine(g, fed, method_config("fedais"), rounds=1, mesh=two_axis)
 
@@ -234,9 +236,11 @@ def test_make_client_mesh_and_axis_resolution():
     mesh = make_client_mesh(1)
     assert dict(mesh.shape) == {CLIENT_AXIS: 1}
     assert client_axis_of(mesh) == CLIENT_AXIS
-    one_axis = jax.make_mesh((1,), ("shards",), devices=jax.devices()[:1])
+    one_axis = jax.make_mesh((1,), ("shards",), (AxisType.Auto,),
+                             devices=jax.devices()[:1])
     assert client_axis_of(one_axis) == "shards"
-    two_axis = jax.make_mesh((1, 1), ("a", "b"), devices=jax.devices()[:1])
+    two_axis = jax.make_mesh((1, 1), ("a", "b"), (AxisType.Auto,) * 2,
+                             devices=jax.devices()[:1])
     assert client_axis_of(two_axis) is None
     with pytest.raises(ValueError, match="devices"):
         make_client_mesh(len(jax.devices()) + 1)

@@ -179,7 +179,8 @@ def test_param_specs_shard_big_dims():
     from repro.sharding.specs import param_spec_tree
     if len(jax.devices()) != 1:
         pytest.skip("expects single-device CPU")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
 
     class Leaf:
         def __init__(self, shape):

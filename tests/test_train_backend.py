@@ -37,6 +37,9 @@ COMM_KEYS = ("comm_total", "comm_embed", "wall_clock")
 # one method per strategy family — the full registry rides the same
 # LocalUpdate, so these pin every code path train_backend touches
 METHODS = ("fedais", "fedall", "fedrandom", "fedpns", "fedsage+")
+# segment-vs-gather loss tier where it is not 1e-4: the methods measured
+# past 1e-4 on jax 0.9.0 / XLA:CPU (see the test)
+SEGMENT_LOSS_RTOL = {"fedall": 1e-3, "fedpns": 1e-3}
 
 N_DEV = len(jax.devices())
 needs_devices = pytest.mark.skipif(
@@ -51,14 +54,14 @@ def _run(g, fed, method="fedais", *, rounds=4, m=4, tau0=4, **kw):
     return eng, eng.run()
 
 
-def _assert_parity(ref, got):
+def _assert_parity(ref, got, *, close_rtol=1e-4):
     assert set(ref.history) == set(got.history)
     for k in ref.history:
         if k in CLOSE_KEYS:
             np.testing.assert_allclose(
                 np.asarray(got.history[k], np.float64),
                 np.asarray(ref.history[k], np.float64),
-                rtol=1e-4, atol=1e-6, err_msg=f"history[{k!r}]")
+                rtol=close_rtol, atol=1e-6, err_msg=f"history[{k!r}]")
         elif k in COMM_KEYS:
             np.testing.assert_allclose(
                 np.asarray(got.history[k], np.float64),
@@ -89,11 +92,18 @@ def test_method_parity_segment_vs_gather(small_fed, method):
     """The in-trace bucketed-CSR segment path trains every method family to
     the same discrete trajectory (which clients ran, which rounds synced,
     what it cost) with losses allclose — summation order is the only
-    difference."""
+    difference.
+
+    Loss tier: 4 rounds of training carry the per-segment summation-order
+    difference into the losses. Measured on jax 0.9.0 / XLA:CPU, the largest
+    relative test-loss gap is 3.1e-4 for fedall and fedpns (round 3); the
+    others stay inside the 1e-4 tier (fedrandom 2.7e-4 on a 1e-3 loss, inside
+    the 1e-6 atol; fedsage+ 1.5e-5, fedais 1.6e-6). Every discrete column is
+    exact."""
     g, fed = small_fed
     _, ref = _run(g, fed, method)
     _, seg = _run(g, fed, method, train_backend="segment")
-    _assert_parity(ref, seg)
+    _assert_parity(ref, seg, close_rtol=SEGMENT_LOSS_RTOL.get(method, 1e-4))
 
 
 def test_tau_gated_rounds_stay_gated_under_segment(small_fed):
