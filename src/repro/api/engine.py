@@ -129,6 +129,7 @@ from repro.sharding.tables import (
     shard_tables_to_mesh,
     sync_round_gates,
 )
+from repro.utils.spans import span
 
 _CLIENT_ARRAY_KEYS = (
     "features", "labels", "node_mask", "train_mask",
@@ -137,8 +138,7 @@ _CLIENT_ARRAY_KEYS = (
 
 # Per-round stats streamed out of the fused scan (everything except the
 # (m, n_max) loss_all table, which stays in the on-device carry as prev_loss).
-_LIGHT_STATS = ("epoch_losses", "n_sync", "n_ghost_pulled",
-                "mean_importance_entropy")
+_LIGHT_STATS = ("epoch_losses", "n_sync", "n_ghost_pulled")
 
 # Default-stack callbacks proven side-effect-free on non-eval rounds (they
 # only act when EvalCallback set ctx.metrics, i.e. at chunk boundaries) —
@@ -210,6 +210,7 @@ class FedEngine:
     keyword; the defaults reproduce the paper's Algorithm 1 exactly.
     """
 
+    @span("fed/engine-build")
     def __init__(
         self,
         graph: GraphData,
@@ -735,17 +736,18 @@ class FedEngine:
                          hist1[sel], age[sel], ghost_feat[sel], prev_loss[sel],
                          tau, fanouts, eoff, keys)
                 new_params, new_hist1, new_age, new_ghost_feat, stats = out
-                params = agg.aggregate(new_params, sizes[sel])
-                loss_wb = stats["loss_all"]
-                if sync_dtype != "fp32":
-                    new_hist1 = quant_roundtrip(new_hist1, sync_dtype)
-                    new_ghost_feat = quant_roundtrip(new_ghost_feat,
-                                                     sync_dtype)
-                    loss_wb = quant_roundtrip(loss_wb, sync_dtype)
-                hist1 = hist1.at[sel].set(new_hist1)
-                age = age.at[sel].set(new_age)
-                ghost_feat = ghost_feat.at[sel].set(new_ghost_feat)
-                prev_loss = prev_loss.at[sel].set(loss_wb)
+                with jax.named_scope("merge"):
+                    params = agg.aggregate(new_params, sizes[sel])
+                    loss_wb = stats["loss_all"]
+                    if sync_dtype != "fp32":
+                        new_hist1 = quant_roundtrip(new_hist1, sync_dtype)
+                        new_ghost_feat = quant_roundtrip(new_ghost_feat,
+                                                         sync_dtype)
+                        loss_wb = quant_roundtrip(loss_wb, sync_dtype)
+                    hist1 = hist1.at[sel].set(new_hist1)
+                    age = age.at[sel].set(new_age)
+                    ghost_feat = ghost_feat.at[sel].set(new_ghost_feat)
+                    prev_loss = prev_loss.at[sel].set(loss_wb)
                 light = {k: stats[k] for k in _LIGHT_STATS}
                 return (params, hist1, age, ghost_feat, prev_loss, key), light
 
@@ -942,97 +944,101 @@ class FedEngine:
         stepwise merge's billing — dropped members are billed nothing,
         stragglers stretch the round's wall clock, survivor-free rounds
         count as empty merges."""
-        sels, fans = [], []
-        for t in range(t0, t0 + n_rounds):
-            state.round = t
-            sel = np.asarray(self.selector.select(self, state))
-            sels.append(sel)
-            fans.append(self.strategy.choose_fanouts(self, sel))
-        if any(len(s) != len(sels[0]) for s in sels):
-            raise ValueError(
-                "fused executor needs constant cohort sizes across a chunk; "
-                "precomputable selectors must return fixed-size cohorts")
-        eoffs = np.arange(t0, t0 + n_rounds, dtype=np.int32) * self.mcfg.local_epochs
+        with span("fed/select", round=t0, rounds=n_rounds):
+            sels, fans = [], []
+            for t in range(t0, t0 + n_rounds):
+                state.round = t
+                sel = np.asarray(self.selector.select(self, state))
+                sels.append(sel)
+                fans.append(self.strategy.choose_fanouts(self, sel))
+            if any(len(s) != len(sels[0]) for s in sels):
+                raise ValueError(
+                    "fused executor needs constant cohort sizes across a chunk; "
+                    "precomputable selectors must return fixed-size cohorts")
+            eoffs = np.arange(t0, t0 + n_rounds, dtype=np.int32) * self.mcfg.local_epochs
 
-        drop_stack = cmask_stack = None
-        if self._faults_active:
-            ts = range(t0, t0 + n_rounds)
-            drop_stack = np.stack(
-                [self.faults.drops(t, s) for t, s in zip(ts, sels)])
-            cmask_stack = np.stack(
-                [self.faults.corruptions(t, s) for t, s in zip(ts, sels)])
-            state.fault_events.n_dropped += int(drop_stack.sum())
-
-        if self.mesh is not None and self.pod_sharded_eligibility(len(sels[0]))[0]:
-            self.last_executor = "pod_sharded"
-            carry, light = self._call_pod_chunk(state, sels, fans, eoffs,
-                                                drop_stack=drop_stack)
-        elif self.mesh is not None and self.sharded_eligibility(len(sels[0]))[0]:
-            self.last_executor = "sharded_fused"
-            carry, light = self._call_sharded_chunk(state, sels, fans, eoffs,
-                                                    drop_stack=drop_stack)
-        elif self._faults_active:
-            self.last_executor = "fused_faulty"
-            carry, light = self._call_faulty_chunk(state, sels, fans, eoffs,
-                                                   drop_stack, cmask_stack)
-        else:
-            self.last_executor = "fused"
-            if self._fused_chunk is None:
-                self._fused_chunk = self._build_fused_chunk()
-            carry, light = self._fused_chunk(
-                state.params, state.hist.hist1, state.hist.age, state.ghost_feat,
-                state.prev_loss, state.key, state.arrays,
-                jnp.asarray(np.stack(sels)), jnp.stack(fans), jnp.asarray(eoffs),
-                jnp.asarray(state.tau, jnp.int32))
-        (state.params, hist1, age, state.ghost_feat, state.prev_loss,
-         state.key) = carry
-        state.hist = state.hist._replace(hist1=hist1, age=age)
-
-        light = jax.device_get(light)       # one host transfer per chunk
-        n_quar_rounds = light.pop("n_quarantined", None)
-        if n_quar_rounds is not None:
-            state.fault_events.n_quarantined += int(np.sum(n_quar_rounds))
-        for i, t in enumerate(range(t0, t0 + n_rounds)):
-            state.round = t
-            stats_t = {k: v[i] for k, v in light.items()}
-            sel_t, stats_b, wall = sels[i], stats_t, None
+            drop_stack = cmask_stack = None
             if self._faults_active:
-                plan = self.faults
-                if drop_stack is not None and drop_stack[i].any():
-                    # dropped uploads never reach the server: bill survivors
-                    keep = np.flatnonzero(~drop_stack[i])
-                    sel_t = sels[i][keep]
-                    stats_b = {k: v[keep] for k, v in stats_t.items()}
-                if plan.straggler_frac > 0.0:
-                    # same formula as the stepwise _inject_faults billing:
-                    # the lockstep server waits for every dispatched member
-                    # (stragglers included; compute times are stats-free in
-                    # PaperCostModel, so the sharded executor's dummy rows
-                    # for dropped members don't leak in), while the merge
-                    # overhead o prices only the survivor uploads
-                    times = np.asarray(self.cost_model.client_compute_times(
-                        self, state, sels[i], stats_t), np.float64)
-                    times = times * plan.delay_factors(sels[i])
-                    o = self.cost_model.sync_overhead(self, sel_t, stats_b)
-                    wall = float(np.max(times)) + o / max(state.tau, 1)
-                n_quar_t = (0 if n_quar_rounds is None
-                            else int(n_quar_rounds[i]))
-                if len(sel_t) - n_quar_t <= 0:
-                    state.fault_events.n_empty_merges += 1
-            if len(sel_t):
-                cost = self.cost_model.round_cost(self, state, sel_t, stats_b)
+                ts = range(t0, t0 + n_rounds)
+                drop_stack = np.stack(
+                    [self.faults.drops(t, s) for t, s in zip(ts, sels)])
+                cmask_stack = np.stack(
+                    [self.faults.corruptions(t, s) for t, s in zip(ts, sels)])
+                state.fault_events.n_dropped += int(drop_stack.sum())
+
+        with span("fed/dispatch", round=t0, rounds=n_rounds):
+            if self.mesh is not None and self.pod_sharded_eligibility(len(sels[0]))[0]:
+                self.last_executor = "pod_sharded"
+                carry, light = self._call_pod_chunk(state, sels, fans, eoffs,
+                                                    drop_stack=drop_stack)
+            elif self.mesh is not None and self.sharded_eligibility(len(sels[0]))[0]:
+                self.last_executor = "sharded_fused"
+                carry, light = self._call_sharded_chunk(state, sels, fans, eoffs,
+                                                        drop_stack=drop_stack)
+            elif self._faults_active:
+                self.last_executor = "fused_faulty"
+                carry, light = self._call_faulty_chunk(state, sels, fans, eoffs,
+                                                       drop_stack, cmask_stack)
             else:
-                cost = CostMeter()
-            if wall is not None:
-                cost.wall_clock_s = wall
-            state.result.costs.add(cost)
-            if len(sel_t):
-                self.strategy.post_round(self, state, sel_t, stats_b)
-            ctx = RoundContext(engine=self, state=state, t=t, rounds=self.rounds)
-            for cb in self.callbacks:
-                cb.on_round_end(ctx)
-            if ctx.stop:
-                return True
+                self.last_executor = "fused"
+                if self._fused_chunk is None:
+                    self._fused_chunk = self._build_fused_chunk()
+                carry, light = self._fused_chunk(
+                    state.params, state.hist.hist1, state.hist.age, state.ghost_feat,
+                    state.prev_loss, state.key, state.arrays,
+                    jnp.asarray(np.stack(sels)), jnp.stack(fans), jnp.asarray(eoffs),
+                    jnp.asarray(state.tau, jnp.int32))
+            (state.params, hist1, age, state.ghost_feat, state.prev_loss,
+             state.key) = carry
+            state.hist = state.hist._replace(hist1=hist1, age=age)
+
+        with span("fed/wait", round=t0, rounds=n_rounds):
+            light = jax.device_get(light)   # one host transfer per chunk
+        with span("fed/replay", round=t0, rounds=n_rounds):
+            n_quar_rounds = light.pop("n_quarantined", None)
+            if n_quar_rounds is not None:
+                state.fault_events.n_quarantined += int(np.sum(n_quar_rounds))
+            for i, t in enumerate(range(t0, t0 + n_rounds)):
+                state.round = t
+                stats_t = {k: v[i] for k, v in light.items()}
+                sel_t, stats_b, wall = sels[i], stats_t, None
+                if self._faults_active:
+                    plan = self.faults
+                    if drop_stack is not None and drop_stack[i].any():
+                        # dropped uploads never reach the server: bill survivors
+                        keep = np.flatnonzero(~drop_stack[i])
+                        sel_t = sels[i][keep]
+                        stats_b = {k: v[keep] for k, v in stats_t.items()}
+                    if plan.straggler_frac > 0.0:
+                        # same formula as the stepwise _inject_faults billing:
+                        # the lockstep server waits for every dispatched member
+                        # (stragglers included; compute times are stats-free in
+                        # PaperCostModel, so the sharded executor's dummy rows
+                        # for dropped members don't leak in), while the merge
+                        # overhead o prices only the survivor uploads
+                        times = np.asarray(self.cost_model.client_compute_times(
+                            self, state, sels[i], stats_t), np.float64)
+                        times = times * plan.delay_factors(sels[i])
+                        o = self.cost_model.sync_overhead(self, sel_t, stats_b)
+                        wall = float(np.max(times)) + o / max(state.tau, 1)
+                    n_quar_t = (0 if n_quar_rounds is None
+                                else int(n_quar_rounds[i]))
+                    if len(sel_t) - n_quar_t <= 0:
+                        state.fault_events.n_empty_merges += 1
+                if len(sel_t):
+                    cost = self.cost_model.round_cost(self, state, sel_t, stats_b)
+                else:
+                    cost = CostMeter()
+                if wall is not None:
+                    cost.wall_clock_s = wall
+                state.result.costs.add(cost)
+                if len(sel_t):
+                    self.strategy.post_round(self, state, sel_t, stats_b)
+                ctx = RoundContext(engine=self, state=state, t=t, rounds=self.rounds)
+                for cb in self.callbacks:
+                    cb.on_round_end(ctx)
+                if ctx.stop:
+                    return True
         return False
 
     def run_fused(self, state: EngineState) -> None:
@@ -1052,6 +1058,7 @@ class FedEngine:
                 return
             t = t_end + 1
 
+    @span("fed/run")
     def run(self, state: EngineState | None = None) -> RunResult:
         if state is None:
             state = self.init_state()

@@ -146,17 +146,19 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
 
         # ---- lines 11-12: loss pass + selection probabilities ----
         all_idx = jnp.arange(n_max)
-        logits_all, _, _ = gcn_batch_forward(
-            params, client["features"], ghost_feat, hist1,
-            client["nbr_idx"], client["nbr_mask"], all_idx,
-            backend=train_backend,
-        )
-        loss_all = per_node_loss(logits_all, client["labels"]) * client["node_mask"]
-        if mcfg.importance_sampling:
-            scores = loss_delta_scores(loss_all, prev_loss, train_mask)
-            probs = importance_probs(scores, train_mask)
-        else:
-            probs = uniform_probs(train_mask)
+        with jax.named_scope("loss_pass"):
+            logits_all, _, _ = gcn_batch_forward(
+                params, client["features"], ghost_feat, hist1,
+                client["nbr_idx"], client["nbr_mask"], all_idx,
+                backend=train_backend,
+            )
+            loss_all = (per_node_loss(logits_all, client["labels"])
+                        * client["node_mask"])
+            if mcfg.importance_sampling:
+                scores = loss_delta_scores(loss_all, prev_loss, train_mask)
+                probs = importance_probs(scores, train_mask)
+            else:
+                probs = uniform_probs(train_mask)
 
         opt_state = adamw_init(params)
         n_sync = jnp.zeros((), jnp.int32)
@@ -197,38 +199,39 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
             # transferred ("the selected cross-client neighbor embeddings",
             # Algorithm 1 line 16) — importance sampling thus directly
             # shrinks the communication volume.
-            j_global = epoch_offset + j
-            do_sync = ((j_global % jnp.maximum(tau, 1)) == 0) & jnp.asarray(
-                mcfg.use_ghosts and not mcfg.use_generator)
+            with jax.named_scope("ghost_pull"):
+                j_global = epoch_offset + j
+                do_sync = ((j_global % jnp.maximum(tau, 1)) == 0) & jnp.asarray(
+                    mcfg.use_ghosts and not mcfg.use_generator)
 
-            b_idx_rows = client["nbr_idx"][batch_idx]
-            referenced = (b_idx_rows >= n_max) & (b_nbr_mask * keep > 0) & valid[:, None]
-            slot = jnp.where(referenced, b_idx_rows - n_max, 0)
-            need = jnp.zeros((g_max,), jnp.float32).at[slot.reshape(-1)].max(
-                referenced.reshape(-1).astype(jnp.float32))
-            need = need * client["ghost_mask"]
+                b_idx_rows = client["nbr_idx"][batch_idx]
+                referenced = (b_idx_rows >= n_max) & (b_nbr_mask * keep > 0) & valid[:, None]
+                slot = jnp.where(referenced, b_idx_rows - n_max, 0)
+                need = jnp.zeros((g_max,), jnp.float32).at[slot.reshape(-1)].max(
+                    referenced.reshape(-1).astype(jnp.float32))
+                need = need * client["ghost_mask"]
 
-            def pull(_):
-                if ghost_source == "tables":
-                    gf, gh = pull_ghosts(hist1_all, feats_all,
-                                         client["ghost_owner"],
-                                         client["ghost_row"],
-                                         client["ghost_mask"])
-                else:
-                    gf, gh = pull_ghosts_prefetched(feats_all, hist1_all,
-                                                    client["ghost_mask"])
-                if sync_dtype != "fp32" and ghost_source == "tables":
-                    gf = quant_roundtrip(gf, sync_dtype)
-                    gh = quant_roundtrip(gh, sync_dtype)
-                new_ghost_feat = jnp.where(need[:, None] > 0, gf, ghost_feat)
-                new_hist = hist1.at[n_max:].set(
-                    jnp.where(need[:, None] > 0, gh, hist1[n_max:]))
-                return new_ghost_feat, new_hist, n_sync + 1, n_pulled + need.sum()
+                def pull(_):
+                    if ghost_source == "tables":
+                        gf, gh = pull_ghosts(hist1_all, feats_all,
+                                             client["ghost_owner"],
+                                             client["ghost_row"],
+                                             client["ghost_mask"])
+                    else:
+                        gf, gh = pull_ghosts_prefetched(feats_all, hist1_all,
+                                                        client["ghost_mask"])
+                    if sync_dtype != "fp32" and ghost_source == "tables":
+                        gf = quant_roundtrip(gf, sync_dtype)
+                        gh = quant_roundtrip(gh, sync_dtype)
+                    new_ghost_feat = jnp.where(need[:, None] > 0, gf, ghost_feat)
+                    new_hist = hist1.at[n_max:].set(
+                        jnp.where(need[:, None] > 0, gh, hist1[n_max:]))
+                    return new_ghost_feat, new_hist, n_sync + 1, n_pulled + need.sum()
 
-            def nopull(_):
-                return ghost_feat, hist1, n_sync, n_pulled
+                def nopull(_):
+                    return ghost_feat, hist1, n_sync, n_pulled
 
-            ghost_feat, hist1, n_sync, n_pulled = jax.lax.cond(do_sync, pull, nopull, None)
+                ghost_feat, hist1, n_sync, n_pulled = jax.lax.cond(do_sync, pull, nopull, None)
 
             # ---- line 18: batch forward/backward + local step ----
             def batch_loss(p):
@@ -250,7 +253,9 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
             return (params, opt_state, hist1, age, ghost_feat, n_sync, n_pulled, key), loss
 
         carry = (params, opt_state, hist1, age, ghost_feat, n_sync, n_ghost_pulled, key)
-        carry, epoch_losses = jax.lax.scan(epoch, carry, jnp.arange(mcfg.local_epochs))
+        with jax.named_scope("local_steps"):
+            carry, epoch_losses = jax.lax.scan(epoch, carry,
+                                               jnp.arange(mcfg.local_epochs))
         params, opt_state, hist1, age, ghost_feat, n_sync, n_ghost_pulled, key = carry
 
         stats = {
@@ -258,8 +263,6 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
             "epoch_losses": epoch_losses,
             "n_sync": n_sync,
             "n_ghost_pulled": n_ghost_pulled,
-            "mean_importance_entropy": -jnp.sum(
-                jnp.where(probs > 0, probs * jnp.log(jnp.maximum(probs, 1e-30)), 0.0)),
         }
         return params, hist1, age, ghost_feat, stats
 

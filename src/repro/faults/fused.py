@@ -76,60 +76,61 @@ def build_faulty_chunk(vm, light_stats: Sequence[str], *,
             new_params = jax.tree_util.tree_map(
                 lambda x: x * bcast(cmult, x).astype(x.dtype), new_params)
 
-            # finite/norm guard over each member's uploaded params
-            if finite_guard:
-                ok = jnp.ones((m,), bool)
-                sumsq = jnp.zeros((m,), jnp.float32)
-                for x, r in zip(jax.tree_util.tree_leaves(new_params),
-                                jax.tree_util.tree_leaves(params)):
-                    flat = x.reshape(m, -1)
-                    ok &= jnp.all(jnp.isfinite(flat), axis=1)
+            with jax.named_scope("merge"):
+                # finite/norm guard over each member's uploaded params
+                if finite_guard:
+                    ok = jnp.ones((m,), bool)
+                    sumsq = jnp.zeros((m,), jnp.float32)
+                    for x, r in zip(jax.tree_util.tree_leaves(new_params),
+                                    jax.tree_util.tree_leaves(params)):
+                        flat = x.reshape(m, -1)
+                        ok &= jnp.all(jnp.isfinite(flat), axis=1)
+                        if max_norm is not None:
+                            d = flat - r.reshape(1, -1)
+                            d = jnp.where(jnp.isfinite(d), d, 0.0)
+                            sumsq += jnp.sum(d * d, axis=1)
                     if max_norm is not None:
-                        d = flat - r.reshape(1, -1)
-                        d = jnp.where(jnp.isfinite(d), d, 0.0)
-                        sumsq += jnp.sum(d * d, axis=1)
-                if max_norm is not None:
-                    ok &= jnp.sqrt(sumsq) <= max_norm
-            else:
-                ok = jnp.ones((m,), bool)
+                        ok &= jnp.sqrt(sumsq) <= max_norm
+                else:
+                    ok = jnp.ones((m,), bool)
 
-            dispatched = w > 0.0                    # not dropped by the plan
-            alive = dispatched & ok
-            n_quar = jnp.sum(dispatched & ~ok)
+                dispatched = w > 0.0                    # not dropped by the plan
+                alive = dispatched & ok
+                n_quar = jnp.sum(dispatched & ~ok)
 
-            # zero non-survivor rows BEFORE weighting: NaN * 0 is NaN, and
-            # a zeroed row added to a float sum is exact — so the masked
-            # full-m merge equals the stepwise survivor-subset merge
-            safe = jax.tree_util.tree_map(
-                lambda x: jnp.where(bcast(alive, x), x, jnp.zeros((), x.dtype)),
-                new_params)
-            wa = jnp.where(alive, w, 0.0)
-            if uses_weights:                        # WeightedFedAvg, exactly
-                wn = wa / jnp.maximum(wa.sum(), 1e-12)
-                merged = jax.tree_util.tree_map(
-                    lambda x: (x * bcast(wn, x)).sum(axis=0), safe)
-            else:                                   # FedAvg (mean), exactly
-                count = jnp.maximum(alive.sum(), 1)
-                merged = jax.tree_util.tree_map(
-                    lambda x: x.sum(axis=0) / count, safe)
-            any_alive = alive.any()
-            params = jax.tree_util.tree_map(
-                lambda mrg, old: jnp.where(any_alive, mrg, old),
-                merged, params)
+                # zero non-survivor rows BEFORE weighting: NaN * 0 is NaN, and
+                # a zeroed row added to a float sum is exact — so the masked
+                # full-m merge equals the stepwise survivor-subset merge
+                safe = jax.tree_util.tree_map(
+                    lambda x: jnp.where(bcast(alive, x), x, jnp.zeros((), x.dtype)),
+                    new_params)
+                wa = jnp.where(alive, w, 0.0)
+                if uses_weights:                        # WeightedFedAvg, exactly
+                    wn = wa / jnp.maximum(wa.sum(), 1e-12)
+                    merged = jax.tree_util.tree_map(
+                        lambda x: (x * bcast(wn, x)).sum(axis=0), safe)
+                else:                                   # FedAvg (mean), exactly
+                    count = jnp.maximum(alive.sum(), 1)
+                    merged = jax.tree_util.tree_map(
+                        lambda x: x.sum(axis=0) / count, safe)
+                any_alive = alive.any()
+                params = jax.tree_util.tree_map(
+                    lambda mrg, old: jnp.where(any_alive, mrg, old),
+                    merged, params)
 
-            # non-survivors lose their write-back too: out-of-range row K
-            # makes the scatter drop (same trick as sharded dummy padding)
-            wb = jnp.where(alive, sel, K)
-            loss_wb = stats["loss_all"]
-            new_hist1_wb, new_ghost_feat_wb = new_hist1, new_ghost_feat
-            if sync_dtype != "fp32":
-                new_hist1_wb = quant_roundtrip(new_hist1, sync_dtype)
-                new_ghost_feat_wb = quant_roundtrip(new_ghost_feat, sync_dtype)
-                loss_wb = quant_roundtrip(loss_wb, sync_dtype)
-            hist1 = hist1.at[wb].set(new_hist1_wb)
-            age = age.at[wb].set(new_age)
-            ghost_feat = ghost_feat.at[wb].set(new_ghost_feat_wb)
-            prev_loss = prev_loss.at[wb].set(loss_wb)
+                # non-survivors lose their write-back too: out-of-range row K
+                # makes the scatter drop (same trick as sharded dummy padding)
+                wb = jnp.where(alive, sel, K)
+                loss_wb = stats["loss_all"]
+                new_hist1_wb, new_ghost_feat_wb = new_hist1, new_ghost_feat
+                if sync_dtype != "fp32":
+                    new_hist1_wb = quant_roundtrip(new_hist1, sync_dtype)
+                    new_ghost_feat_wb = quant_roundtrip(new_ghost_feat, sync_dtype)
+                    loss_wb = quant_roundtrip(loss_wb, sync_dtype)
+                hist1 = hist1.at[wb].set(new_hist1_wb)
+                age = age.at[wb].set(new_age)
+                ghost_feat = ghost_feat.at[wb].set(new_ghost_feat_wb)
+                prev_loss = prev_loss.at[wb].set(loss_wb)
 
             light = {k: stats[k] for k in light_stats}
             light["n_quarantined"] = n_quar

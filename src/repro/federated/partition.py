@@ -25,6 +25,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.graph.data import GraphData
+from repro.utils.spans import span
 
 
 def pod_table_padding(n_clients: int, n_pods: int) -> int:
@@ -346,6 +347,7 @@ class FederatedGraph:
         return self.node_mask.sum(axis=1).astype(np.int32)
 
 
+@span("fed/partition")
 def partition_graph(
     graph: GraphData,
     n_clients: int,

@@ -10,6 +10,7 @@ import numpy as np
 
 from repro.core.sync import adaptive_tau
 from repro.models.gcn import AGG_BACKENDS, gcn_full_forward, per_node_loss
+from repro.utils.spans import span
 
 
 def select_clients(rng: np.random.Generator, n_clients: int, m: int) -> np.ndarray:
@@ -77,6 +78,7 @@ def _eval_logits(params, features, nbr_idx, nbr_mask, csr=None, adj=None,
                             backend=backend, csr=csr, adj=adj)
 
 
+@span("fed/eval")
 def evaluate_global(params, eval_graph: dict, split: str = "test") -> dict:
     logits = _eval_logits(params, eval_graph["features"],
                           eval_graph["nbr_idx"], eval_graph["nbr_mask"],
@@ -85,7 +87,9 @@ def evaluate_global(params, eval_graph: dict, split: str = "test") -> dict:
                           backend=eval_graph.get("backend", "gather"))
     mask = np.asarray(eval_graph[f"{split}_mask"])
     labels = np.asarray(eval_graph["labels"])[mask]
-    lg = np.asarray(logits, np.float32)[mask]
+    with span("fed/eval-wait"):
+        lg = np.asarray(logits, np.float32)
+    lg = lg[mask]
     nll = np.asarray(per_node_loss(jnp.asarray(lg), jnp.asarray(labels)))
     pred = lg.argmax(-1)
     acc = float((pred == labels).mean()) if len(labels) else 0.0

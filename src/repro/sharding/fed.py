@@ -147,8 +147,9 @@ def _client_step(vm, mesh: Mesh, axis: str, reduce: str):
         out = vm(params, client, feats_all, hist1_all, h1s, ages, gfs, pls,
                  tau, fanouts, eoff, keys)
         new_params, new_hist1, new_age, new_ghost, stats = out
-        wmean = weighted_merge(axis, w, reduce)
-        agg = jax.tree_util.tree_map(wmean, new_params, params)
+        with jax.named_scope("merge"):
+            wmean = weighted_merge(axis, w, reduce)
+            agg = jax.tree_util.tree_map(wmean, new_params, params)
         return agg, new_hist1, new_age, new_ghost, stats
 
     c, r = P(axis), P()
@@ -206,16 +207,17 @@ def build_sharded_chunk(vm, mesh: Mesh, axis: str, m_real: int,
                        hist1[sel], age[sel], ghost_feat[sel], prev_loss[sel],
                        tau, fanouts, eoff, keys, w)
             params, new_hist1, new_age, new_ghost_feat, stats = out
-            loss_wb = stats["loss_all"]
-            if sync_dtype != "fp32":
-                new_hist1 = quant_roundtrip(new_hist1, sync_dtype)
-                new_ghost_feat = quant_roundtrip(new_ghost_feat, sync_dtype)
-                loss_wb = quant_roundtrip(loss_wb, sync_dtype)
-            # out-of-range padding ids make these scatters drop, never land
-            hist1 = hist1.at[sel].set(new_hist1)
-            age = age.at[sel].set(new_age)
-            ghost_feat = ghost_feat.at[sel].set(new_ghost_feat)
-            prev_loss = prev_loss.at[sel].set(loss_wb)
+            with jax.named_scope("merge"):
+                loss_wb = stats["loss_all"]
+                if sync_dtype != "fp32":
+                    new_hist1 = quant_roundtrip(new_hist1, sync_dtype)
+                    new_ghost_feat = quant_roundtrip(new_ghost_feat, sync_dtype)
+                    loss_wb = quant_roundtrip(loss_wb, sync_dtype)
+                # out-of-range padding ids make these scatters drop, never land
+                hist1 = hist1.at[sel].set(new_hist1)
+                age = age.at[sel].set(new_age)
+                ghost_feat = ghost_feat.at[sel].set(new_ghost_feat)
+                prev_loss = prev_loss.at[sel].set(loss_wb)
             light = {k: stats[k][:m_real] for k in light_stats}
             return (params, hist1, age, ghost_feat, prev_loss, key), light
 

@@ -240,8 +240,9 @@ def _pod_step(vm, mesh: Mesh, buckets: GhostBuckets, reduce: str,
         new_params, new_hist1, new_age, new_gfeat, stats = out
 
         # ---- aggregation: weighted all-reduce, or fp32 pairwise tree ----
-        wmean = weighted_merge(axes, w, reduce)
-        agg = jax.tree_util.tree_map(wmean, new_params, params)
+        with jax.named_scope("merge"):
+            wmean = weighted_merge(axes, w, reduce)
+            agg = jax.tree_util.tree_map(wmean, new_params, params)
 
         # ---- cohort-keyed bucket write-back ----
         # stage 1: gather the pod row's cohort slice (m/P rows) across the
@@ -275,10 +276,11 @@ def _pod_step(vm, mesh: Mesh, buckets: GhostBuckets, reduce: str,
                 rows = route(fresh)
             return table.at[tgt].set(rows)
 
-        hist_sh = write_back(hist_sh, new_hist1)
-        age_sh = write_back(age_sh, new_age)
-        gfeat_sh = write_back(gfeat_sh, new_gfeat)
-        pl_sh = write_back(pl_sh, stats["loss_all"])
+        with jax.named_scope("merge"):
+            hist_sh = write_back(hist_sh, new_hist1)
+            age_sh = write_back(age_sh, new_age)
+            gfeat_sh = write_back(gfeat_sh, new_gfeat)
+            pl_sh = write_back(pl_sh, stats["loss_all"])
         return agg, hist_sh, age_sh, gfeat_sh, pl_sh, stats
 
     t, c, r = P(POD_AXIS), P(axes), P()

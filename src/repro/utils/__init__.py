@@ -1,1 +1,1 @@
-"""Shared utilities: pytree helpers, HLO analysis, roofline math."""
+"""Shared utilities: pytree helpers, HLO analysis, roofline math, host spans."""
