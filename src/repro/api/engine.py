@@ -134,6 +134,7 @@ from repro.utils.spans import span
 _CLIENT_ARRAY_KEYS = (
     "features", "labels", "node_mask", "train_mask",
     "nbr_idx", "nbr_mask", "ghost_owner", "ghost_row", "ghost_mask",
+    "loss_idx", "loss_mask", "loss_pos",
 )
 
 # Per-round stats streamed out of the fused scan (everything except the
@@ -367,7 +368,8 @@ class FedEngine:
         # compilation each)
         self._vm_raw = make_vmapped_update(self.mcfg, fed.n_max, fed.g_max,
                                            self.H1, sync_dtype=self.sync_dtype,
-                                           train_backend=self.train_backend)
+                                           train_backend=self.train_backend,
+                                           loss_buckets=fed.loss_buckets)
         self._vm = jax.jit(self._vm_raw)
         self._fused_chunk = None            # built lazily by run_fused
         self._sharded_chunk = None          # built lazily when mesh is set
@@ -851,7 +853,8 @@ class FedEngine:
                                      self.fed.g_max, self.H1,
                                      ghost_source="prefetched",
                                      sync_dtype=self.sync_dtype,
-                                     train_backend=self.train_backend)
+                                     train_backend=self.train_backend,
+                                     loss_buckets=self.fed.loss_buckets)
             self._pod_chunk = build_pod_sharded_chunk(
                 vm, mesh, m, buckets, _LIGHT_STATS,
                 reduce=self.merge_reduce, sync_dtype=self.sync_dtype)

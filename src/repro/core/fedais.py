@@ -28,7 +28,12 @@ from repro.core.importance import (
     stable_rank,
     uniform_probs,
 )
-from repro.models.gcn import AGG_BACKENDS, gcn_batch_forward, per_node_loss
+from repro.models.gcn import (
+    AGG_BACKENDS,
+    gcn_batch_forward,
+    gcn_loss_pass_forward,
+    per_node_loss,
+)
 from repro.optim import adamw_init, adamw_update
 
 
@@ -70,7 +75,8 @@ VMAP_IN_AXES_PREFETCHED = (None, 0, 0, 0, 0, 0, 0, 0, None, 0, None, 0)
 def make_vmapped_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                         *, ghost_source: str = "tables",
                         sync_dtype: str = "fp32",
-                        train_backend: str = "gather"):
+                        train_backend: str = "gather",
+                        loss_buckets: tuple | None = None):
     """The cohort-stacked LocalUpdate every executor vmaps over the selected
     clients — shared by the engine's stepwise/fused paths and the sharded
     round_step (repro.sharding.fed), so all of them run one computation.
@@ -80,14 +86,16 @@ def make_vmapped_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
     return jax.vmap(make_local_update(mcfg, n_max, g_max, h1_dim,
                                       ghost_source=ghost_source,
                                       sync_dtype=sync_dtype,
-                                      train_backend=train_backend),
+                                      train_backend=train_backend,
+                                      loss_buckets=loss_buckets),
                     in_axes=axes)
 
 
 def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                       *, ghost_source: str = "tables",
                       sync_dtype: str = "fp32",
-                      train_backend: str = "gather"):
+                      train_backend: str = "gather",
+                      loss_buckets: tuple | None = None):
     """Build the jit-able LocalUpdate for one client (Algorithm 1 lines 10-19).
 
     ``ghost_source`` picks where the tau-gated embedding sync reads from:
@@ -109,13 +117,19 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
     all-to-all and the partition-time feature exchange), so this function
     applies no second round-trip. ``"fp32"`` adds zero trace ops.
 
-    ``train_backend`` selects the *batch* neighbor aggregation inside both
-    ``gcn_batch_forward`` calls (the per-epoch loss pass and the training
-    step): ``gather`` is the bit-parity default; ``segment`` derives its
-    jit-stable bucketed CSR in-trace from the sampled batch rows and never
-    materializes the (b, K, d) gather; ``spmm`` runs the Pallas kernel
+    ``train_backend`` selects the neighbor aggregation of the loss pass
+    and the training step: ``gather`` is the bit-parity default; ``segment``
+    derives its jit-stable bucketed CSR in-trace from the batch rows and
+    never materializes the (b, K, d) gather; ``spmm`` runs the Pallas kernel
     (grads flow through its custom VJP). Allclose parity across backends is
     pinned per method by tests/test_train_backend.py.
+
+    ``loss_buckets`` is the partition's static degree-bucket geometry
+    (``FederatedGraph.loss_buckets``). Given it, the client arrays carry the
+    layout (``loss_idx``, ``loss_mask``, ``loss_pos``) and the ``gather``
+    backend's loss pass reads it (``gcn_loss_pass_forward``) instead of
+    gathering all K padded slots of every row; the training step's rows and
+    fanout are drawn in-trace, so it keeps ``gcn_batch_forward``.
     """
     if ghost_source not in ("tables", "prefetched"):
         raise ValueError(f"unknown ghost_source {ghost_source!r}; "
@@ -147,11 +161,17 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
         # ---- lines 11-12: loss pass + selection probabilities ----
         all_idx = jnp.arange(n_max)
         with jax.named_scope("loss_pass"):
-            logits_all, _, _ = gcn_batch_forward(
-                params, client["features"], ghost_feat, hist1,
-                client["nbr_idx"], client["nbr_mask"], all_idx,
-                backend=train_backend,
-            )
+            if loss_buckets is not None and train_backend == "gather":
+                logits_all, _ = gcn_loss_pass_forward(
+                    params, client["features"], ghost_feat, hist1,
+                    client["loss_idx"], client["loss_mask"],
+                    client["loss_pos"], loss_buckets)
+            else:
+                logits_all, _, _ = gcn_batch_forward(
+                    params, client["features"], ghost_feat, hist1,
+                    client["nbr_idx"], client["nbr_mask"], all_idx,
+                    backend=train_backend,
+                )
             loss_all = (per_node_loss(logits_all, client["labels"])
                         * client["node_mask"])
             if mcfg.importance_sampling:

@@ -13,6 +13,9 @@ Layout per client k (padded to the max over clients):
     ghost_owner (g_max,)      owning client id (-1 pad)
     ghost_row   (g_max,)      row index within the owner's local arrays
     ghost_mask  (g_max,)
+    loss_idx   (S,)           the loss pass's degree buckets' neighbour slots
+    loss_mask  (S,)
+    loss_pos   (n_max,)       each row's place in the bucket outputs
 
 The combined embedding table a client sees is [own rows | ghost rows] of
 size n_max + g_max — exactly the paper's Eq. (6) split into within-client
@@ -26,6 +29,65 @@ import numpy as np
 
 from repro.graph.data import GraphData
 from repro.utils.spans import span
+
+
+# nominal neighbour-slot widths of the loss pass's degree buckets: a row of
+# degree d sits in the first bucket whose width is >= d; the last bucket holds
+# every degree above 16 at the full max_deg width
+LOSS_PASS_WIDTHS = (1, 2, 4, 8, 16, 32)
+
+
+def loss_pass_layout(nbr_idx: np.ndarray, nbr_mask: np.ndarray):
+    """The loss pass's degree-bucketed neighbour layout, from the packed
+    (K, n_max, D) neighbour rows (real slots first in every row).
+
+    Each client's rows of degree > 0 are grouped by degree into the
+    ``LOSS_PASS_WIDTHS`` buckets (widths capped at D; the last bucket is D
+    wide). A bucket's capacity is the largest count of its rows over the K
+    clients, so the shapes are static and shared under vmap. Returns
+    ``(idx, mask, pos, buckets)``:
+
+    * ``idx``, ``mask`` (K, S) int32 / float32: each bucket's (capacity,
+      width) block of neighbour slots, the first ``width`` slots of its
+      rows in natural row order, flattened bucket after bucket; the
+      entries past a client's count are masked.
+    * ``pos`` (K, n_max) int32: where each row's aggregate lies among the
+      concatenated bucket outputs; rows of degree 0 point at the zero row
+      appended after them.
+    * ``buckets``: ``((width, capacity), ...)``, static; S is the sum of
+      width times capacity.
+
+    The slots are copied here, once, so that the loss pass gathers no
+    neighbour row in the trace (XLA lowers such a gather of a few slots
+    per row to a loop over the rows on the TPU). The build runs in a
+    ``fed/loss-pass-layout`` span whose arguments are the neighbour slots
+    one client's loss pass gathers (``gathered_slots``, S) and the padded
+    slots it gathered without the layout (``padded_slots``).
+    """
+    K, n_max, D = nbr_mask.shape
+    deg = (nbr_mask > 0).sum(-1)
+    widths = tuple(min(w, D) for w in LOSS_PASS_WIDTHS[:-1]) + (D,)
+    bucket = np.searchsorted(np.asarray(LOSS_PASS_WIDTHS[:-1]), deg)
+    members = [(deg > 0) & (bucket == b) for b in range(len(widths))]
+    caps = [int(m.sum(1).max(initial=0)) for m in members]
+    buckets = tuple(zip(widths, caps))
+    S = sum(w * c for w, c in buckets)
+    with span("fed/loss-pass-layout", gathered_slots=S,
+              padded_slots=n_max * D):
+        idx = np.zeros((K, S), np.int32)
+        mask = np.zeros((K, S), np.float32)
+        pos = np.full((K, n_max), sum(caps), np.int32)
+        slot = row = 0
+        for m, (w, cap) in zip(members, buckets):
+            k, i = np.nonzero(m)
+            at = np.cumsum(m, axis=1)[k, i] - 1
+            block = slot + at[:, None] * w + np.arange(w)
+            idx[k[:, None], block] = nbr_idx[k, i, :w]
+            mask[k[:, None], block] = nbr_mask[k, i, :w]
+            pos[k, i] = row + at
+            slot += w * cap
+            row += cap
+    return idx, mask, pos, buckets
 
 
 def pod_table_padding(n_clients: int, n_pods: int) -> int:
@@ -337,6 +399,10 @@ class FederatedGraph:
     global_ids: np.ndarray   # (K, n_max) original node id (-1 pad)
     n_classes: int
     n_cross_edges: int       # Table-1 style ΔE diagnostic
+    loss_idx: np.ndarray     # (K, S) loss-pass bucket neighbour slots
+    loss_mask: np.ndarray    # (K, S)
+    loss_pos: np.ndarray     # (K, n_max) place of each row's aggregate
+    loss_buckets: tuple      # ((width, capacity), ...), static
 
     @property
     def n_features(self) -> int:
@@ -446,11 +512,15 @@ def partition_graph(
             nbr_idx[k, i, : len(nbrs)] = nbrs
             nbr_mask[k, i, : len(nbrs)] = 1.0
 
+    loss_idx, loss_mask, loss_pos, loss_buckets = loss_pass_layout(nbr_idx,
+                                                                   nbr_mask)
+
     return FederatedGraph(
         name=graph.name, n_clients=n_clients, n_max=n_max, g_max=g_max,
         max_deg=max_deg, features=feats, labels=labels, node_mask=node_mask,
         train_mask=train_mask, val_mask=val_mask, nbr_idx=nbr_idx,
         nbr_mask=nbr_mask, ghost_owner=ghost_owner, ghost_row=ghost_row,
         ghost_mask=ghost_mask, global_ids=global_ids, n_classes=graph.n_classes,
-        n_cross_edges=int(len(cross)),
+        n_cross_edges=int(len(cross)), loss_idx=loss_idx,
+        loss_mask=loss_mask, loss_pos=loss_pos, loss_buckets=loss_buckets,
     )

@@ -154,6 +154,49 @@ def gcn_batch_forward(
     return logits, h1, h2
 
 
+def gcn_loss_pass_forward(
+    params: dict,
+    features: jnp.ndarray,      # (n, F) own features
+    ghost_feat: jnp.ndarray,    # (g, F) synced ghost features (historical l=0)
+    hist1: jnp.ndarray,         # (n + g, H1) historical layer-1 embeddings
+    idx: jnp.ndarray,           # (S,) the buckets' neighbour slots
+    mask: jnp.ndarray,          # (S,)
+    pos: jnp.ndarray,           # (n,) each row's place in the bucket outputs
+    buckets: tuple,             # ((width, capacity), ...), static
+):
+    """Returns (logits (n, C), h1 (n, H1)) of every row of one client: the
+    loss pass, ``gcn_batch_forward`` over ``arange(n)`` with the ``gather``
+    backend, over the degree-bucketed layout of
+    ``federated.partition.loss_pass_layout``.
+
+    Each bucket holds the first ``width`` slots of its rows, which hold all
+    of their real neighbours. One gather per layer fetches every bucket's
+    slots; each bucket then takes the masked mean over its slots, as
+    ``_aggregate`` does without the masked zeros (XLA may order the sum
+    otherwise, so a row can differ from the padded form in its last bits).
+    One gather of n rows puts the aggregates back in row order. A gather
+    per bucket instead stalled the round chunk at silo16's sizes on a TPU
+    v5e.
+    """
+    def agg(table):
+        gathered = table[idx] * mask[:, None]
+        parts, start = [], 0
+        for width, cap in buckets:
+            if cap:
+                end = start + width * cap
+                deg = mask[start:end].reshape(cap, width).sum(-1, keepdims=True)
+                parts.append(gathered[start:end].reshape(cap, width, -1).sum(1)
+                             / jnp.maximum(deg, 1.0))
+                start = end
+        parts.append(jnp.zeros((1, table.shape[1]), table.dtype))
+        return jnp.concatenate(parts)[pos]
+
+    h1 = _sage_layer(params, 0, features,
+                     agg(jnp.concatenate([features, ghost_feat], axis=0)))
+    h2 = _sage_layer(params, 1, h1, agg(hist1.at[:features.shape[0]].set(h1)))
+    return h2 @ params["w_cls"] + params["b_cls"], h1
+
+
 def gcn_full_forward(params, features, nbr_idx, nbr_mask, *,
                      backend: str = "gather", csr: dict | None = None,
                      adj: jnp.ndarray | None = None,

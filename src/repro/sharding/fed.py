@@ -230,11 +230,14 @@ def build_sharded_chunk(vm, mesh: Mesh, axis: str, m_real: int,
 
 def abstract_chunk_args(mesh: Mesh, *, n_clients: int, cohort: int,
                         n_max: int, g_max: int, n_feat: int, n_classes: int,
-                        max_deg: int = 16, rounds: int = 1):
+                        max_deg: int = 16, rounds: int = 1,
+                        loss_buckets: tuple | None = None):
     """ShapeDtypeStructs (with replicated NamedShardings) matching
     ``build_sharded_chunk``'s signature, for lowering the chunk without
     real data — the dry-run path. ``cohort`` is the padded cohort size the
-    chunk receives (a multiple of the mesh's client axis)."""
+    chunk receives (a multiple of the mesh's client axis). ``loss_buckets``
+    adds the loss-pass layout arrays a LocalUpdate built with the same
+    geometry reads."""
     from repro.models.gcn import HIDDEN, gcn_init
 
     r = NamedSharding(mesh, P())
@@ -259,6 +262,11 @@ def abstract_chunk_args(mesh: Mesh, *, n_clients: int, cohort: int,
         "ghost_row": sds((K, g_max), jnp.int32),
         "ghost_mask": sds((K, g_max), jnp.float32),
     }
+    if loss_buckets is not None:
+        S = sum(w * c for w, c in loss_buckets)
+        arrays["loss_idx"] = sds((K, S), jnp.int32)
+        arrays["loss_mask"] = sds((K, S), jnp.float32)
+        arrays["loss_pos"] = sds((K, n_max), jnp.int32)
     return (
         params,
         sds((K, n_tot, HIDDEN[0]), jnp.float32),   # hist1

@@ -75,7 +75,8 @@ POD_AXIS = "pods"
 # exchanges already routed by them on the host), so those two stay off the
 # mesh entirely.
 POD_ARRAY_KEYS = ("features", "labels", "node_mask", "train_mask",
-                  "nbr_idx", "nbr_mask", "ghost_mask")
+                  "nbr_idx", "nbr_mask", "ghost_mask", "loss_idx", "loss_mask",
+                  "loss_pos")
 
 
 def make_pod_mesh(n_pods: int, n_client_shards: Optional[int] = None) -> Mesh:
@@ -358,14 +359,16 @@ def abstract_pod_chunk_args(mesh: Mesh, buckets: GhostBuckets, *,
                             n_clients: int, cohort: int, n_max: int,
                             g_max: int, n_feat: int, n_classes: int,
                             max_deg: int = 16, rounds: int = 1,
-                            wb_cap: Optional[int] = None):
+                            wb_cap: Optional[int] = None,
+                            loss_buckets: Optional[tuple] = None):
     """ShapeDtypeStructs matching ``build_pod_sharded_chunk``'s signature:
     the four tables, the static client arrays, and the ghost-source table
     all padded to ``buckets.n_clients_padded`` rows with ``P("pods")``
     NamedShardings; cohort stacks, sync gates, and write-back routing
     replicated. ``wb_cap`` fixes the bucket capacity (default: the
-    worst-case pow2(cohort / P) — every slice row owned by one pod). The
-    ``--pods`` dry-run path."""
+    worst-case pow2(cohort / P) — every slice row owned by one pod).
+    ``loss_buckets`` adds the loss-pass layout arrays. The ``--pods``
+    dry-run path."""
     from repro.models.gcn import HIDDEN, gcn_init
 
     P_ = mesh.shape[POD_AXIS]
@@ -396,6 +399,11 @@ def abstract_pod_chunk_args(mesh: Mesh, buckets: GhostBuckets, *,
         "nbr_mask": ts((Kp, n_max, max_deg), jnp.float32),
         "ghost_mask": ts((Kp, g_max), jnp.float32),
     }
+    if loss_buckets is not None:
+        S = sum(w * c for w, c in loss_buckets)
+        arrays["loss_idx"] = ts((Kp, S), jnp.int32)
+        arrays["loss_mask"] = ts((Kp, S), jnp.float32)
+        arrays["loss_pos"] = ts((Kp, n_max), jnp.int32)
     return (
         params,
         ts((Kp, n_tot, HIDDEN[0]), jnp.float32),   # hist1

@@ -5,6 +5,7 @@ import re
 import subprocess
 import sys
 import threading
+import types
 
 import jax
 import numpy as np
@@ -15,7 +16,11 @@ from repro.api.callbacks import EvalCallback
 from repro.api.engine import _LIGHT_STATS
 from repro.core.fedais import make_vmapped_update
 from repro.faults import build_faulty_chunk
-from repro.federated.partition import ghost_exchange_buckets, partition_graph
+from repro.federated.partition import (
+    ghost_exchange_buckets,
+    loss_pass_layout,
+    partition_graph,
+)
 from repro.graph.data import make_dataset
 from repro.models.gcn import HIDDEN
 from repro.sharding.fed import (
@@ -123,6 +128,45 @@ def test_record_to_restores_the_sink_attached_before():
     assert [n for n, _, _ in outer] == ["fed/b"]
 
 
+def test_layout_span_carries_the_slot_counts(monkeypatch):
+    """``fed/loss-pass-layout`` hands the profiler the neighbour slots one
+    client's loss pass gathers and the padded slots it would gather."""
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            seen.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setitem(sys.modules, "jax.profiler",
+                        types.SimpleNamespace(TraceAnnotation=Annotation))
+    # degrees 1, 3, 0 and 4, 2, 0 over 4 slots: buckets of width 1, 2 and
+    # 4 hold one row each
+    deg = np.array([[1, 3, 0], [4, 2, 0]])
+    mask = (np.arange(4) < deg[..., None]).astype(np.float32)
+    sink = []
+    with record_to(sink):
+        *_, buckets = loss_pass_layout(mask.astype(np.int32), mask)
+    assert buckets == ((1, 1), (2, 1), (4, 1), (4, 0), (4, 0), (4, 0))
+    assert seen == [("fed/loss-pass-layout",
+                     {"gathered_slots": 7, "padded_slots": 12})]
+    assert [n for n, _, _ in sink] == ["fed/loss-pass-layout"]
+
+
+def test_partition_records_its_layout_span():
+    g = make_dataset("pubmed", scale=64, seed=0)
+    sink = []
+    with record_to(sink):
+        partition_graph(g, 4, alpha=0.5, seed=0)
+    assert [n for n, _, _ in sink] == ["fed/loss-pass-layout",
+                                       "fed/partition"]
+
+
 @pytest.fixture(scope="module")
 def tiny():
     g = make_dataset("pubmed", scale=64, seed=0)
@@ -211,9 +255,11 @@ def test_sharded_chunks_carry_every_scope(tiny):
     mcfg = method_config("fedais", tau0=2, batch_cap=16)
     dims = dict(n_clients=fed.n_clients, cohort=2, n_max=fed.n_max,
                 g_max=fed.g_max, n_feat=fed.n_features,
-                n_classes=fed.n_classes, max_deg=fed.max_deg)
+                n_classes=fed.n_classes, max_deg=fed.max_deg,
+                loss_buckets=fed.loss_buckets)
     mesh = make_client_mesh(1)
-    vm = make_vmapped_update(mcfg, fed.n_max, fed.g_max, HIDDEN[0])
+    vm = make_vmapped_update(mcfg, fed.n_max, fed.g_max, HIDDEN[0],
+                             loss_buckets=fed.loss_buckets)
     chunk = build_sharded_chunk(vm, mesh, "clients", 2, _LIGHT_STATS)
     hlo = chunk.lower(*abstract_chunk_args(mesh, **dims)).compile().as_text()
     assert _scopes_in(hlo) == set(SCOPES)
@@ -222,7 +268,8 @@ def test_sharded_chunks_carry_every_scope(tiny):
     buckets = ghost_exchange_buckets(fed.ghost_owner, fed.ghost_row,
                                      fed.ghost_mask, 1)
     vm = make_vmapped_update(mcfg, fed.n_max, fed.g_max, HIDDEN[0],
-                             ghost_source="prefetched")
+                             ghost_source="prefetched",
+                             loss_buckets=fed.loss_buckets)
     chunk = build_pod_sharded_chunk(vm, pods, 2, buckets, _LIGHT_STATS)
     hlo = chunk.lower(*abstract_pod_chunk_args(pods, buckets, **dims)
                       ).compile().as_text()
