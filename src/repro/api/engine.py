@@ -88,7 +88,12 @@ from repro.api.registry import (
     build_strategy,
     method_config,
 )
-from repro.core.fedais import MethodConfig, batch_size_for, make_vmapped_update
+from repro.core.fedais import (
+    MethodConfig,
+    batch_size_for,
+    fanout_width,
+    make_vmapped_update,
+)
 from repro.core.historical import init_historical
 from repro.faults import (
     FaultCounters,
@@ -139,7 +144,7 @@ _CLIENT_ARRAY_KEYS = (
 
 # Per-round stats streamed out of the fused scan (everything except the
 # (m, n_max) loss_all table, which stays in the on-device carry as prev_loss).
-_LIGHT_STATS = ("epoch_losses", "n_sync", "n_ghost_pulled")
+_LIGHT_STATS = ("epoch_losses", "n_sync", "n_ghost_pulled", "n_kept_slots")
 
 # Default-stack callbacks proven side-effect-free on non-eval rounds (they
 # only act when EvalCallback set ctx.metrics, i.e. at chunk boundaries) —
@@ -362,6 +367,9 @@ class FedEngine:
         avg_deg = float(fed.nbr_mask.sum() / np.maximum(fed.node_mask.sum(), 1))
         self.fwd_flops_node = gcn_flops_per_node(self.F, fed.n_classes, avg_deg)
         self.bsz = batch_size_for(self.mcfg, fed.n_max)
+        # W: the neighbor slots a batch row's aggregation reads per epoch
+        self.fanout_width = fanout_width(self.strategy.max_fanout(self),
+                                         fed.max_deg)
         # the raw vmapped step is shared by every executor: the stepwise path
         # jits it standalone, the fused path traces it inside the scanned
         # round_step, the sharded path shard_maps it (same computation, one
@@ -369,7 +377,8 @@ class FedEngine:
         self._vm_raw = make_vmapped_update(self.mcfg, fed.n_max, fed.g_max,
                                            self.H1, sync_dtype=self.sync_dtype,
                                            train_backend=self.train_backend,
-                                           loss_buckets=fed.loss_buckets)
+                                           loss_buckets=fed.loss_buckets,
+                                           width=self.fanout_width)
         self._vm = jax.jit(self._vm_raw)
         self._fused_chunk = None            # built lazily by run_fused
         self._sharded_chunk = None          # built lazily when mesh is set
@@ -854,7 +863,8 @@ class FedEngine:
                                      ghost_source="prefetched",
                                      sync_dtype=self.sync_dtype,
                                      train_backend=self.train_backend,
-                                     loss_buckets=self.fed.loss_buckets)
+                                     loss_buckets=self.fed.loss_buckets,
+                                     width=self.fanout_width)
             self._pod_chunk = build_pod_sharded_chunk(
                 vm, mesh, m, buckets, _LIGHT_STATS,
                 reduce=self.merge_reduce, sync_dtype=self.sync_dtype)

@@ -37,6 +37,17 @@ class MethodStrategy:
         """Per-selected-client neighbor fanout for this round."""
         return jnp.full((len(sel),), self.mcfg.neighbor_fanout, jnp.int32)
 
+    def max_fanout(self, engine: "FedEngine") -> int:
+        """Largest fanout ``choose_fanouts`` can return. It sets the width
+        of the training step's neighbor lists (``FedEngine.fanout_width``),
+        and a row keeps no more slots than that: ``neighbor_fanout`` for the
+        fixed fanout; a subclass with its own ``choose_fanouts`` gets
+        ``max_deg``, every padded slot, unless it overrides this with its
+        policy's bound."""
+        if type(self).choose_fanouts is MethodStrategy.choose_fanouts:
+            return self.mcfg.neighbor_fanout
+        return engine.fed.max_deg
+
     def pre_round(self, engine: "FedEngine", state: "EngineState",
                   sel: np.ndarray) -> None:
         """Before the vmapped LocalUpdate (e.g. ghost-feature imputation)."""
@@ -118,6 +129,9 @@ class BanditStrategy(MethodStrategy):
 
     def choose_fanouts(self, engine, sel):
         return jnp.asarray([self.bandit.choose(int(k)) for k in sel], jnp.int32)
+
+    def max_fanout(self, engine):
+        return max(B.FANOUT_ACTIONS)
 
     def post_round(self, engine, state, sel, stats):
         mean_losses = np.asarray(stats["epoch_losses"]).mean(axis=1)

@@ -31,6 +31,7 @@ from repro.core.importance import (
 from repro.models.gcn import (
     AGG_BACKENDS,
     gcn_batch_forward,
+    gcn_batch_rows_forward,
     gcn_loss_pass_forward,
     per_node_loss,
 )
@@ -64,6 +65,56 @@ def batch_size_for(mcfg: MethodConfig, n_max: int) -> int:
     return max(1, min(mcfg.batch_cap, int(round(n_max * mcfg.sample_ratio))))
 
 
+def fanout_width(max_fanout: int, max_deg: int) -> int:
+    """Static width ``W`` of the training step's neighbor lists: the most
+    slots a batch row can keep, ``min(max_deg, max_fanout)``, where
+    ``max_fanout`` bounds the fanouts the method's strategy passes to
+    LocalUpdate (``MethodStrategy.max_fanout``). Below ``max_deg`` the step
+    compacts each row's kept slots to ``W`` before it gathers a feature or
+    embedding row; at ``max_deg`` it aggregates the padded rows under a
+    keep mask."""
+    return max(1, min(max_deg, int(max_fanout)))
+
+
+def kept_neighbors(b_idx: jnp.ndarray, b_mask: jnp.ndarray,
+                   ranks: jnp.ndarray, fanout, width: int):
+    """The batch rows' kept neighbor lists ``(idx, mask)``, ``width`` wide.
+
+    ``b_idx``/``b_mask`` are the rows' padded (b, K) lists and ``ranks`` a
+    (b, K) key per slot, padding keyed last. A row keeps its real slots at
+    positions < ``fanout`` of the keys' stable ascending order (one top-k
+    over mantissa-quantized keys, ``importance.stable_rank``; near-equal
+    draws tie and resolve by slot index). At ``width == K`` the padded
+    lists come back with the other slots masked. Below K the kept slots
+    are packed to the front of (b, width) lists, in slot order, before any
+    feature or embedding row is gathered, so a row's aggregate adds its
+    neighbours in the padded form's order, without the masked zeros. A
+    fanout above ``width`` keeps ``width`` slots.
+    """
+    k = b_idx.shape[-1]
+    if width >= k:
+        return b_idx, b_mask * (stable_rank(ranks) < fanout)
+    keep = (b_mask > 0) & (stable_rank(ranks) < jnp.minimum(fanout, width))
+    # a one-hot pick over the (b, W, K) ints, not an int gather, which can
+    # lower to a loop of one dynamic-slice per row on a TPU
+    slots = jnp.arange(width)
+    pos = jnp.cumsum(keep, axis=-1) - 1
+    pick = keep[:, None, :] & (pos[:, None, :] == slots[:, None])
+    c_idx = jnp.where(pick, b_idx[:, None, :], 0).sum(-1)
+    c_mask = (slots < keep.sum(-1, keepdims=True)).astype(b_mask.dtype)
+    return c_idx, c_mask
+
+
+def ghost_need(b_idx: jnp.ndarray, b_mask: jnp.ndarray, valid: jnp.ndarray,
+               n_max: int, ghost_mask: jnp.ndarray) -> jnp.ndarray:
+    """(g_max,) 1 where a valid batch row's kept neighbor is that ghost."""
+    referenced = (b_idx >= n_max) & (b_mask > 0) & valid[:, None]
+    slot = jnp.where(referenced, b_idx - n_max, 0)
+    need = jnp.zeros(ghost_mask.shape, jnp.float32).at[slot.reshape(-1)].max(
+        referenced.reshape(-1).astype(jnp.float32))
+    return need * ghost_mask
+
+
 # vmap axes of local_update over the selected-client cohort: per-client
 # slices map on their leading axis; params / full tables / scalars broadcast
 VMAP_IN_AXES = (None, 0, None, None, 0, 0, 0, 0, None, 0, None, 0)
@@ -76,7 +127,8 @@ def make_vmapped_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                         *, ghost_source: str = "tables",
                         sync_dtype: str = "fp32",
                         train_backend: str = "gather",
-                        loss_buckets: tuple | None = None):
+                        loss_buckets: tuple | None = None,
+                        width: int | None = None):
     """The cohort-stacked LocalUpdate every executor vmaps over the selected
     clients — shared by the engine's stepwise/fused paths and the sharded
     round_step (repro.sharding.fed), so all of them run one computation.
@@ -87,7 +139,8 @@ def make_vmapped_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                                       ghost_source=ghost_source,
                                       sync_dtype=sync_dtype,
                                       train_backend=train_backend,
-                                      loss_buckets=loss_buckets),
+                                      loss_buckets=loss_buckets,
+                                      width=width),
                     in_axes=axes)
 
 
@@ -95,7 +148,8 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                       *, ghost_source: str = "tables",
                       sync_dtype: str = "fp32",
                       train_backend: str = "gather",
-                      loss_buckets: tuple | None = None):
+                      loss_buckets: tuple | None = None,
+                      width: int | None = None):
     """Build the jit-able LocalUpdate for one client (Algorithm 1 lines 10-19).
 
     ``ghost_source`` picks where the tau-gated embedding sync reads from:
@@ -128,8 +182,16 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
     (``FederatedGraph.loss_buckets``). Given it, the client arrays carry the
     layout (``loss_idx``, ``loss_mask``, ``loss_pos``) and the ``gather``
     backend's loss pass reads it (``gcn_loss_pass_forward``) instead of
-    gathering all K padded slots of every row; the training step's rows and
-    fanout are drawn in-trace, so it keeps ``gcn_batch_forward``.
+    gathering all K padded slots of every row.
+
+    The training step's rows and fanout are drawn in-trace. ``width`` is
+    the most slots a row keeps, at least every fanout the step is passed
+    (``fanout_width``; the engine's ``FedEngine.fanout_width``). Below the
+    K padded slots, each epoch compacts every batch row's kept slots to
+    ``width`` columns (``gcn_batch_rows_forward``), so its forward and
+    backward run on (b, W) neighbor lists; the draws and the kept set are
+    those of the padded form. None keeps the K padded slots under a keep
+    mask. ``stats["n_kept_slots"]`` counts the real slots read.
     """
     if ghost_source not in ("tables", "prefetched"):
         raise ValueError(f"unknown ghost_source {ghost_source!r}; "
@@ -183,9 +245,13 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
         opt_state = adamw_init(params)
         n_sync = jnp.zeros((), jnp.int32)
         n_ghost_pulled = jnp.zeros((), jnp.float32)
+        n_kept_slots = jnp.zeros((), jnp.float32)
+        max_deg = client["nbr_idx"].shape[-1]
+        w = max_deg if width is None else fanout_width(width, max_deg)
 
         def epoch(carry, j):
-            params, opt_state, hist1, age, ghost_feat, n_sync, n_pulled, key = carry
+            (params, opt_state, hist1, age, ghost_feat, n_sync, n_pulled,
+             n_kept, key) = carry
             key, k_batch, k_nbr = jax.random.split(key, 3)
 
             # ---- line 14: batch selection ----
@@ -196,20 +262,13 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                 batch_idx, valid = sample_batch(k_batch, probs, bsz, train_mask)
 
             # ---- neighbor fanout subsampling ----
+            b_idx = client["nbr_idx"][batch_idx]
             b_nbr_mask = client["nbr_mask"][batch_idx]
             ranks = jax.random.uniform(k_nbr, b_nbr_mask.shape)
             ranks = jnp.where(b_nbr_mask > 0, ranks, 2.0)
-            # one stable top-k over mantissa-quantized keys (see
-            # importance.stable_rank) instead of the old double argsort over
-            # raw keys. NOTE: quantization coarsens the keys, so near-equal
-            # draws can tie and resolve by slot index where the raw-key path
-            # ordered them by value — seeded trajectories differ from the
-            # pre-quantization code (deliberate: same jitter-insensitivity
-            # scheme as sample_batch; tests pin new-vs-old on shared keys)
-            order = stable_rank(ranks)
-            keep = (order < fanout).astype(jnp.float32)
+            b_idx, b_mask = kept_neighbors(b_idx, b_nbr_mask, ranks, fanout, w)
             if not mcfg.use_ghosts:
-                keep = keep * (client["nbr_idx"][batch_idx] < n_max)
+                b_mask = b_mask * (b_idx < n_max)
 
             # ---- lines 15-17: sync every tau epochs (pull ghosts) ----
             # j is the GLOBAL batch-epoch counter (Algorithm 1: the paper's j
@@ -224,12 +283,8 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
                 do_sync = ((j_global % jnp.maximum(tau, 1)) == 0) & jnp.asarray(
                     mcfg.use_ghosts and not mcfg.use_generator)
 
-                b_idx_rows = client["nbr_idx"][batch_idx]
-                referenced = (b_idx_rows >= n_max) & (b_nbr_mask * keep > 0) & valid[:, None]
-                slot = jnp.where(referenced, b_idx_rows - n_max, 0)
-                need = jnp.zeros((g_max,), jnp.float32).at[slot.reshape(-1)].max(
-                    referenced.reshape(-1).astype(jnp.float32))
-                need = need * client["ghost_mask"]
+                need = ghost_need(b_idx, b_mask, valid, n_max,
+                                  client["ghost_mask"])
 
                 def pull(_):
                     if ghost_source == "tables":
@@ -255,10 +310,9 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
 
             # ---- line 18: batch forward/backward + local step ----
             def batch_loss(p):
-                logits, h1, _ = gcn_batch_forward(
-                    p, client["features"], ghost_feat, hist1,
-                    client["nbr_idx"], client["nbr_mask"], batch_idx,
-                    nbr_keep=keep, backend=train_backend,
+                logits, h1, _ = gcn_batch_rows_forward(
+                    p, client["features"], ghost_feat, hist1, b_idx, b_mask,
+                    batch_idx, backend=train_backend,
                 )
                 w = valid.astype(jnp.float32) * train_mask[batch_idx]
                 nll = per_node_loss(logits, client["labels"][batch_idx])
@@ -270,19 +324,25 @@ def make_local_update(mcfg: MethodConfig, n_max: int, g_max: int, h1_dim: int,
             # ---- historical push of fresh in-batch embeddings ----
             hist1, age = push_embeddings(hist1, age, batch_idx, h1,
                                          valid & (client["node_mask"][batch_idx] > 0))
-            return (params, opt_state, hist1, age, ghost_feat, n_sync, n_pulled, key), loss
+            return (params, opt_state, hist1, age, ghost_feat, n_sync, n_pulled,
+                    n_kept + b_mask.sum(), key), loss
 
-        carry = (params, opt_state, hist1, age, ghost_feat, n_sync, n_ghost_pulled, key)
+        carry = (params, opt_state, hist1, age, ghost_feat, n_sync,
+                 n_ghost_pulled, n_kept_slots, key)
         with jax.named_scope("local_steps"):
             carry, epoch_losses = jax.lax.scan(epoch, carry,
                                                jnp.arange(mcfg.local_epochs))
-        params, opt_state, hist1, age, ghost_feat, n_sync, n_ghost_pulled, key = carry
+        (params, opt_state, hist1, age, ghost_feat, n_sync, n_ghost_pulled,
+         n_kept_slots, key) = carry
 
         stats = {
             "loss_all": loss_all,                 # becomes prev_loss next round
             "epoch_losses": epoch_losses,
             "n_sync": n_sync,
             "n_ghost_pulled": n_ghost_pulled,
+            # real slots the batch aggregation read, summed over the epochs:
+            # over epochs * bsz * W, the share of gathered slots that were real
+            "n_kept_slots": n_kept_slots,
         }
         return params, hist1, age, ghost_feat, stats
 

@@ -415,7 +415,8 @@ def dryrun_mesh(mesh_name: str, args) -> dict:
                                           fill=args.ghost_fill)
         vm = make_vmapped_update(mcfg, args.n_max, args.g_max, HIDDEN[0],
                                  ghost_source="prefetched",
-                                 sync_dtype=sync_dtype)
+                                 sync_dtype=sync_dtype,
+                                 width=mcfg.neighbor_fanout)
         chunk = build_pod_sharded_chunk(vm, mesh, m, buckets, _LIGHT_STATS,
                                         sync_dtype=sync_dtype)
         sargs = abstract_pod_chunk_args(
@@ -426,7 +427,8 @@ def dryrun_mesh(mesh_name: str, args) -> dict:
         mesh = make_client_mesh(chips)
         axis = client_axis_of(mesh)
         vm = make_vmapped_update(mcfg, args.n_max, args.g_max, HIDDEN[0],
-                                 sync_dtype=sync_dtype)
+                                 sync_dtype=sync_dtype,
+                                 width=mcfg.neighbor_fanout)
         chunk = build_sharded_chunk(vm, mesh, axis, m_real=m,
                                     light_stats=_LIGHT_STATS,
                                     sync_dtype=sync_dtype)

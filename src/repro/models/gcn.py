@@ -108,24 +108,43 @@ def gcn_batch_forward(
     nbr_idx: jnp.ndarray,       # (n, K) into [own | ghost]
     nbr_mask: jnp.ndarray,      # (n, K)
     batch_idx: jnp.ndarray,     # (b,) rows of this batch
-    nbr_keep: jnp.ndarray | None = None,   # optional (b, K) extra neighbor mask
+    *,
+    backend: str = "gather",
+    interpret: bool | None = None,
+):
+    """Returns (logits (b, C), fresh_h1 (b, H1), h2 (b, H2)):
+    ``gcn_batch_rows_forward`` over the batch rows' own neighbour lists
+    ``nbr_idx[batch_idx]``, ``nbr_mask[batch_idx]``."""
+    return gcn_batch_rows_forward(
+        params, features, ghost_feat, hist1, nbr_idx[batch_idx],
+        nbr_mask[batch_idx], batch_idx, backend=backend, interpret=interpret)
+
+
+def gcn_batch_rows_forward(
+    params: dict,
+    features: jnp.ndarray,      # (n, F) own features
+    ghost_feat: jnp.ndarray,    # (g, F) synced ghost features (historical l=0)
+    hist1: jnp.ndarray,         # (n + g, H1) historical layer-1 embeddings
+    b_idx: jnp.ndarray,         # (b, W) the batch rows' neighbours, into [own | ghost]
+    b_mask: jnp.ndarray,        # (b, W)
+    batch_idx: jnp.ndarray,     # (b,) rows of this batch
     *,
     backend: str = "gather",
     interpret: bool | None = None,
 ):
     """Returns (logits (b, C), fresh_h1 (b, H1), h2 (b, H2)).
 
+    The neighbour lists are the batch's own, of any static width ``W``: the
+    padded rows of the partition (``gcn_batch_forward``) or the training
+    step's kept slots compacted to ``W`` (``core.fedais``).
+
     ``backend`` picks the batch neighbor aggregation (``neighbor_aggregate``):
-    the batch shapes (b, K) are static under jit even when ``batch_idx`` is
+    the batch shapes (b, W) are static under jit even when ``batch_idx`` is
     traced, so the segment backend's bucketed CSR and the spmm backend's
     (b, n_tot) adjacency are derived in-trace, once, and shared by both
     layers (layer 0's and layer 1's tables have the same row count).
     """
     table0 = jnp.concatenate([features, ghost_feat], axis=0)
-    b_idx = nbr_idx[batch_idx]
-    b_mask = nbr_mask[batch_idx]
-    if nbr_keep is not None:
-        b_mask = b_mask * nbr_keep
 
     csr = adj = None
     if backend == "segment":

@@ -10,7 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.engine import RunResult
-from repro.core.fedais import MethodConfig, batch_size_for, make_local_update
+from repro.core.fedais import (
+    MethodConfig,
+    batch_size_for,
+    fanout_width,
+    make_local_update,
+)
 from repro.core.historical import init_historical
 from repro.federated import baselines as B
 from repro.federated.costs import CostMeter, DelayModel, embed_sync_bytes, model_bytes
@@ -67,7 +72,10 @@ def legacy_run_federated(
     ghost_feat = jnp.zeros((K, g_max, F), jnp.float32)
     prev_loss = jnp.full((K, n_max), -1.0, jnp.float32)
 
-    local_update = make_local_update(mcfg, n_max, g_max, H1)
+    # the engine's neighbor-list width: the largest fanout the method passes
+    most = max(B.FANOUT_ACTIONS) if mcfg.bandit_fanout else mcfg.neighbor_fanout
+    local_update = make_local_update(mcfg, n_max, g_max, H1,
+                                     width=fanout_width(most, fed.max_deg))
     vm = jax.jit(jax.vmap(local_update,
                           in_axes=(None, 0, None, None, 0, 0, 0, 0, None, 0, None, 0)))
 
